@@ -11,26 +11,24 @@ wall-clock does not scale the way the paper's Fugaku CMGs do — the curve's
 value is (a) the per-strategy dispatch/synchronization overhead at each
 device count on identical work, and (b) a smoke-level proof that both
 strategies run, re-bucket and stay budget-correct on a real multi-device
-mesh.  ``main`` re-execs itself in a subprocess with the XLA flag set (the
-device count must precede jax's first import), so callers like
-``benchmarks/run.py --smoke`` keep their own single-device jax state.
+mesh.  It runs in the calling process on the devices that process sees
+(``--devices`` caps the curve, default all of them); on a CPU the virtual
+fleet comes from the environment:
 
-  PYTHONPATH=src python -m benchmarks.bench_mesh [--devices 8] [--dim 16]
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python -m benchmarks.bench_mesh [--devices 8] [--dim 16]
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
 import sys
-
-_INNER_ENV = "_BENCH_MESH_INNER"
 
 
 def _parser():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="largest device count of the curve (default: all)")
     ap.add_argument("--dim", type=int, default=16)
     ap.add_argument("--fids", default="1,8")
     ap.add_argument("--runs", type=int, default=4)
@@ -43,28 +41,7 @@ def _parser():
 
 
 def main(argv=None):
-    """Outer entry: spawn the real benchmark with the virtual-device flag."""
     args = _parser().parse_args(argv)
-    if os.environ.get(_INNER_ENV) == "1":
-        return _inner(args)
-    env = dict(os.environ)
-    env[_INNER_ENV] = "1"
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={args.devices} "
-        + env.get("XLA_FLAGS", ""))
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = (os.path.join(root, "src") + os.pathsep
-                         + env.get("PYTHONPATH", ""))
-    cmd = [sys.executable, os.path.abspath(__file__)]
-    if argv is not None:
-        cmd += list(argv)
-    else:
-        cmd += sys.argv[1:]
-    subprocess.run(cmd, check=True, env=env, cwd=root)
-    return 0
-
-
-def _inner(args):
     import time
 
     import jax
@@ -81,8 +58,13 @@ def _inner(args):
     kw = dict(n=args.dim, lam_start=args.lam_start, kmax_exp=args.kmax,
               max_evals=args.max_evals, eigen_interval=args.eigen_interval)
     devs = jax.devices()
-    assert len(devs) >= args.devices, devs
-    counts = [d for d in (1, 2, 4, 8, 16, 32) if d <= args.devices]
+    n_dev = len(devs) if args.devices is None else args.devices
+    if n_dev > len(devs):
+        raise SystemExit(
+            f"--devices {n_dev}: only {len(devs)} local devices (for a CPU "
+            f"rehearsal set XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={n_dev})")
+    counts = [d for d in (1, 2, 4, 8, 16, 32) if d <= n_dev]
 
     def timed(fn):
         fn()                                    # warm (compile) pass

@@ -6,7 +6,11 @@ Default sizes are CI-scale (single CPU core); --full widens dims/functions
 to the paper's ranges (hours on this container, intended for real hardware).
 --smoke runs the engine/kernel benchmarks only (a few minutes) and writes
 the BENCH_kernels/BENCH_ladder/BENCH_bucketed/BENCH_mesh/BENCH_service
-JSON artifacts for CI.
+JSON artifacts for CI, every section in this one process.  The mesh
+section covers as many devices as the process sees:
+
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      JAX_PLATFORMS=cpu python benchmarks/run.py --smoke
 """
 from __future__ import annotations
 
@@ -25,6 +29,8 @@ import jax  # noqa: E402
 
 jax.config.update("jax_enable_x64", True)
 
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
+
 
 def section(title):
     print(f"\n=== {title} " + "=" * max(1, 60 - len(title)), flush=True)
@@ -36,6 +42,7 @@ def main(argv=None):
     ap.add_argument("--smoke", action="store_true",
                     help="ladder bench only; writes BENCH_ladder.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     t0 = time.time()
 
     if args.smoke:
@@ -60,10 +67,10 @@ def main(argv=None):
                                     "--kmax", "4", "--max-evals", "20000",
                                     "--eigen-interval", "5", "--out",
                                     "BENCH_bucketed.json"])
-        section("Smoke — mesh campaign engine, S1/S2 on 1→8 virtual devices")
-        # re-execs itself in a subprocess with the 8-device XLA flag, so this
-        # process keeps its single-device jax state
-        bench_mesh.main(["--devices", "8", "--dim", "8", "--fids", "1,8",
+        section("Smoke — mesh campaign engine, S1/S2 on 1→P devices")
+        # in this process, on every device it sees (no child process may
+        # need the chip); a CPU run gets its virtual fleet from XLA_FLAGS
+        bench_mesh.main(["--dim", "8", "--fids", "1,8",
                          "--runs", "4", "--lam-start", "8", "--kmax", "2",
                          "--max-evals", "6000", "--eigen-interval", "3",
                          "--out", "BENCH_mesh.json"])

@@ -20,6 +20,7 @@ import numpy as np
 from repro.core import ladder
 from repro.core.strategies import KReplicated
 from repro.fitness import bbob
+from repro.launch.compile_cache import enable_compile_cache
 
 TARGETS = np.array([1e2, 1e1, 1e0, 1e-1, 1e-2])
 
@@ -36,6 +37,7 @@ def hits_from_trace(best_over_time, evals_over_time, f_opt):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fids", default="1,8,10")
     ap.add_argument("--dim", type=int, default=10)

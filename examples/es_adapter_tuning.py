@@ -20,10 +20,12 @@ from repro.core import cmaes
 from repro.core.params import CMAConfig, make_params
 from repro.data.pipeline import SyntheticTokens
 from repro.fitness.nn_fitness import make_nn_fitness
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 
 
 def main():
+    enable_compile_cache()
     cfg = smoke_config("qwen2-0.5b")
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     data = SyntheticTokens(cfg, seq_len=32, global_batch=4, seed=1)

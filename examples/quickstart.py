@@ -15,11 +15,13 @@ import numpy as np
 from repro.core.ipop import run_ipop
 from repro.core.strategies import KDistributed
 from repro.fitness import bbob
+from repro.launch.compile_cache import enable_compile_cache
 
 FID, DIM, DEVICES = 8, 10, 8         # Rosenbrock, the paper's dims start at 10
 
 
 def main():
+    enable_compile_cache()
     inst = bbob.make_instance(FID, DIM, instance=1)
     fitness = lambda X: bbob.evaluate(FID, inst, X)
     f_opt = float(inst.f_opt)

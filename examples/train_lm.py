@@ -14,12 +14,14 @@ import dataclasses
 import shutil
 
 from repro.configs import smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import optimizer as opt_mod
 from repro.train import train_step as ts_mod
 from repro.train.trainer import Trainer, TrainerConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")

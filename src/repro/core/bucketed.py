@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -489,6 +489,7 @@ def run_bucketed_single(engine: BucketedLadderEngine, base_key: jax.Array,
 def run_campaign_bucketed(engine: BucketedLadderEngine, fids,
                           instances=(1,), runs: int = 1, seed: int = 0,
                           max_segments: int = 10_000,
+                          rows: Optional[Sequence[int]] = None,
                           ) -> BucketedCampaignResult:
     """Run a whole BBOB campaign through the rung-bucketed segment driver.
 
@@ -497,16 +498,23 @@ def run_campaign_bucketed(engine: BucketedLadderEngine, fids,
     arithmetic per generation at ``eigen_interval == 1``, modulo per-shape
     XLA fusion rounding); this driver just never pays λ_max padding on a
     λ_start rung and stops as soon as the whole cohort is done.
+
+    ``rows`` runs only those members of the layout, with their own keys and
+    instances and the whole campaign's fitness menu: e.g. the slice one
+    mesh device holds, in a program of that slice's batch shape.
     """
     fids = tuple(fids)
     members = [(f, i, r) for f in fids for i in instances for r in range(runs)]
+    base = jax.random.PRNGKey(seed)
+    keys = jnp.stack([jax.random.fold_in(base, j) for j in range(len(members))])
+    if rows is not None:
+        rows = [int(j) for j in rows]
+        members = [members[j] for j in rows]
+        keys = keys[np.asarray(rows)]
     insts = [bbob.make_instance(f, engine.n, i, engine.full.cfg.jdtype)
              for (f, i, _r) in members]
     stacked = bbob.stack_instances(insts)
     branch_fids = tuple(sorted(set(fids)))
-
-    base = jax.random.PRNGKey(seed)
-    keys = jnp.stack([jax.random.fold_in(base, j) for j in range(len(members))])
     carry = engine._init_runner(keys)
 
     fused_menu = (bbob.eval_fusion_enabled()

@@ -51,17 +51,9 @@ class FusableEval:
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    New jax exposes it at the top level with ``check_vma``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check)
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def flat_index(axes: AxisNames) -> jnp.ndarray:
@@ -70,15 +62,9 @@ def flat_index(axes: AxisNames) -> jnp.ndarray:
 
 
 def axis_size(axes: AxisNames) -> int:
-    # jax.lax.axis_size only exists in newer jax; psum of the constant 1 is
-    # the portable spelling and constant-folds to a python int at trace time.
-    if hasattr(jax.lax, "axis_size"):
-        sizes = [jax.lax.axis_size(a) for a in axes]
-    else:
-        sizes = [jax.lax.psum(1, a) for a in axes]
     out = 1
-    for s in sizes:
-        out *= int(s)
+    for a in axes:
+        out *= int(jax.lax.axis_size(a))
     return out
 
 

@@ -12,8 +12,9 @@ strategies for running many IPOP-CMA-ES searches on a large machine:
   that re-buckets), preserving the bucketed driver's sequential rung
   ordering exactly.  Inside the program each device vmaps
   ``BucketedLadderEngine.segment_scan`` over its member slice; the budget
-  and best-f scalars are reduced with replicated ``psum``/``pmin`` so every
-  shard (and the host) sees the campaign-global values.
+  and best-f scalars are reduced (a ``psum``, and the min of an
+  ``all_gather``) to replicated values, so every shard (and the host) sees
+  the campaign-global values.
 * ``strategy="concurrent"`` (S2 — the paper's winner): each device is an
   island owning a contiguous member slice and drives its OWN budget-adaptive
   segment schedule — the host round-robins over islands, dispatching each
@@ -255,8 +256,8 @@ class MeshCampaignEngine:
                        fitness_fn: Optional[Callable] = None,
                        cache: Optional[dict] = None):
         """One S1 segment as a ``shard_map`` program over the whole mesh:
-        member batch sharded over ``axis``, budget/best scalars psum/pmin-
-        reduced to replicated outputs.  Cached per (bucket, length, fids) —
+        member batch sharded over ``axis``, budget/best scalars reduced to
+        replicated outputs.  Cached per (bucket, length, fids) —
         jit-cache size 1 per entry, so ``compiles ≤ #buckets`` holds at the
         executable level (asserted in tests/mesh_check.py)."""
         cache = self._runner_cache if cache is None else cache
@@ -270,7 +271,9 @@ class MeshCampaignEngine:
             def local_seg(*args):
                 c, tr = vmapped(*args)
                 g_fev = jax.lax.psum(jnp.sum(c.total_fevals), axis)
-                g_best = jax.lax.pmin(jnp.min(c.best_f), axis)
+                # min of the gathered per-shard bests: the TPU lowers only
+                # sum all-reduces of f64, so a pmin of it does not compile
+                g_best = jnp.min(jax.lax.all_gather(jnp.min(c.best_f), axis))
                 return c, tr, g_fev, g_best
 
             fn = shard_map_compat(

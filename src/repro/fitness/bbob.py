@@ -68,8 +68,11 @@ class BBOBInstance(NamedTuple):
 
 def t_osz(x):
     xhat = jnp.where(x != 0.0, jnp.log(jnp.abs(jnp.where(x != 0.0, x, 1.0))), 0.0)
-    c1 = jnp.where(x > 0.0, 10.0, 5.5)
-    c2 = jnp.where(x > 0.0, 7.9, 3.1)
+    # constants in x's dtype: a weak-typed where() would be f64 under x64,
+    # which the t_osz in the eval-fused Pallas epilogue cannot hold
+    dt = x.dtype
+    c1 = jnp.where(x > 0.0, jnp.asarray(10.0, dt), jnp.asarray(5.5, dt))
+    c2 = jnp.where(x > 0.0, jnp.asarray(7.9, dt), jnp.asarray(3.1, dt))
     return jnp.sign(x) * jnp.exp(
         xhat + 0.049 * (jnp.sin(c1 * xhat) + jnp.sin(c2 * xhat)))
 

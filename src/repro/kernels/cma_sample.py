@@ -20,6 +20,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 import jax.numpy as jnp
 
+from repro.kernels.cma_gen import _block, _dot, _kernel_dtype, _pad, _smem
+
 
 def _kernel(coef_ref, z_ref, d_ref, b_ref, m_ref, x_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -29,17 +31,15 @@ def _kernel(coef_ref, z_ref, d_ref, b_ref, m_ref, x_ref, acc_ref, *, n_k: int):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     z = z_ref[...].astype(jnp.float32)          # (bl, bk)
-    d = d_ref[...].astype(jnp.float32)          # (bk,)
+    d = d_ref[...].astype(jnp.float32)          # (1, bk)
     b = b_ref[...].astype(jnp.float32)          # (bj, bk)
-    acc_ref[...] += jax.lax.dot_general(
-        z * d[None, :], b, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(z * d, b, ((1,), (1,)))
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         sigma = coef_ref[0]
-        m = m_ref[...].astype(jnp.float32)       # (bj,)
-        x_ref[...] = (m[None, :] + sigma * acc_ref[...]).astype(x_ref.dtype)
+        m = m_ref[...].astype(jnp.float32)       # (1, bj)
+        x_ref[...] = (m + sigma * acc_ref[...]).astype(x_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bl", "bj", "bk", "interpret"))
@@ -49,6 +49,7 @@ def cma_sample(m: jnp.ndarray, sigma: jnp.ndarray, B: jnp.ndarray,
     """X = m + σ·(B·diag(D))·Z, row convention (lam, n).  Pads to block shape."""
     lam, n = Z.shape
     dt = Z.dtype
+    kdt = _kernel_dtype(dt, interpret)
     bl = min(bl, max(8, lam))
     bj = min(bj, n)
     bk = min(bk, n)
@@ -57,10 +58,10 @@ def cma_sample(m: jnp.ndarray, sigma: jnp.ndarray, B: jnp.ndarray,
     pk_n = -(-n // bk) * bk
     if pl_n != pk_n:
         pl_n = pk_n = max(pl_n, pk_n)
-    Zp = jnp.zeros((pl_lam, pk_n), dt).at[:lam, :n].set(Z)
-    Bp = jnp.zeros((pl_n, pk_n), dt).at[:n, :n].set(B)
-    Dp = jnp.zeros((pk_n,), dt).at[:n].set(D)
-    Mp = jnp.zeros((pl_n,), dt).at[:n].set(m)
+    Zp = _pad(Z, (pl_lam, pk_n), kdt)
+    Bp = _pad(B, (pl_n, pk_n), kdt)
+    Dp = _pad(D[None], (1, pk_n), kdt)       # vectors as rows: a 1-D block
+    Mp = _pad(m[None], (1, pl_n), kdt)       # misses XLA's 1-D tiling
     coef = jnp.asarray([sigma], jnp.float32)
 
     n_l, n_j, n_k = pl_lam // bl, pl_n // bj, pk_n // bk
@@ -68,15 +69,15 @@ def cma_sample(m: jnp.ndarray, sigma: jnp.ndarray, B: jnp.ndarray,
         functools.partial(_kernel, n_k=n_k),
         grid=(n_l, n_j, n_k),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),               # coef (1,)
-            pl.BlockSpec((bl, bk), lambda l, j, k: (l, k)),      # Z
-            pl.BlockSpec((bk,), lambda l, j, k: (k,)),           # D
-            pl.BlockSpec((bj, bk), lambda l, j, k: (j, k)),      # B
-            pl.BlockSpec((bj,), lambda l, j, k: (j,)),           # m
+            _smem((1,)),                                         # coef (1,)
+            _block((bl, bk), lambda l, j, k: (l, k)),      # Z
+            _block((1, bk), lambda l, j, k: (0, k)),       # D
+            _block((bj, bk), lambda l, j, k: (j, k)),      # B
+            _block((1, bj), lambda l, j, k: (0, j)),       # m
         ],
-        out_specs=pl.BlockSpec((bl, bj), lambda l, j, k: (l, j)),
-        out_shape=jax.ShapeDtypeStruct((pl_lam, pl_n), dt),
+        out_specs=_block((bl, bj), lambda l, j, k: (l, j)),
+        out_shape=jax.ShapeDtypeStruct((pl_lam, pl_n), kdt),
         scratch_shapes=[pltpu.VMEM((bl, bj), jnp.float32)],
         interpret=interpret,
     )(coef, Zp, Dp, Bp, Mp)
-    return out[:lam, :n]
+    return out[:lam, :n].astype(dt)

@@ -22,6 +22,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 import jax.numpy as jnp
 
+from repro.kernels.cma_gen import _block, _dot, _kernel_dtype, _pad, _smem
+
 
 def _kernel(coef_ref, yi_ref, yj_ref, w_ref, c_ref, pci_ref, pcj_ref,
             out_ref, acc_ref, *, n_k: int):
@@ -33,19 +35,17 @@ def _kernel(coef_ref, yi_ref, yj_ref, w_ref, c_ref, pci_ref, pcj_ref,
 
     yi = yi_ref[...].astype(jnp.float32)        # (bk, bi)
     yj = yj_ref[...].astype(jnp.float32)        # (bk, bj)
-    w = w_ref[...].astype(jnp.float32)          # (bk,)
+    w = w_ref[...].astype(jnp.float32)          # (bk, 1)
     # (bi, bj) += Yᵢᵀ · diag(w) · Yⱼ — one MXU contraction per k-step
-    acc_ref[...] += jax.lax.dot_general(
-        yi, yj * w[:, None], (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc_ref[...] += _dot(yi, yj * w, ((0,), (0,)))
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         decay, c_mu, c_1 = coef_ref[0], coef_ref[1], coef_ref[2]
         c = c_ref[...].astype(jnp.float32)       # (bi, bj)
-        pci = pci_ref[...].astype(jnp.float32)   # (bi,)
-        pcj = pcj_ref[...].astype(jnp.float32)   # (bj,)
-        out = decay * c + c_mu * acc_ref[...] + c_1 * pci[:, None] * pcj[None, :]
+        pci = pci_ref[...].astype(jnp.float32)   # (bi, 1)
+        pcj = pcj_ref[...].astype(jnp.float32)   # (1, bj)
+        out = decay * c + c_mu * acc_ref[...] + c_1 * pci * pcj
         out_ref[...] = out.astype(out_ref.dtype)
 
 
@@ -57,6 +57,7 @@ def cma_rank_mu_update(C: jnp.ndarray, Y: jnp.ndarray, w: jnp.ndarray,
     """Fused covariance adaptation.  Y: (λ, n) rows are yᵢ; w: (λ,) rank weights."""
     lam, n = Y.shape
     dt = C.dtype
+    kdt = _kernel_dtype(dt, interpret)
     bi = min(bi, n)
     bj = min(bj, n)
     bk = min(bk, max(8, lam))
@@ -64,10 +65,12 @@ def cma_rank_mu_update(C: jnp.ndarray, Y: jnp.ndarray, w: jnp.ndarray,
     p_n_j = -(-n // bj) * bj
     p_n = max(p_n_i, p_n_j)
     p_lam = -(-lam // bk) * bk
-    Yp = jnp.zeros((p_lam, p_n), dt).at[:lam, :n].set(Y)
-    wp = jnp.zeros((p_lam,), dt).at[:lam].set(w)        # zero weight ⇒ no effect
-    Cp = jnp.zeros((p_n, p_n), dt).at[:n, :n].set(C)
-    pcp = jnp.zeros((p_n,), dt).at[:n].set(p_c)
+    Yp = _pad(Y, (p_lam, p_n), kdt)
+    # vectors as columns/rows: a 1-D block misses XLA's 1-D tiling
+    wp = _pad(w[:, None], (p_lam, 1), kdt)              # zero weight ⇒ no effect
+    Cp = _pad(C, (p_n, p_n), kdt)
+    pc_col = _pad(p_c[:, None], (p_n, 1), kdt)
+    pc_row = _pad(p_c[None], (1, p_n), kdt)
     coef = jnp.stack([jnp.asarray(decay, jnp.float32),
                       jnp.asarray(c_mu, jnp.float32),
                       jnp.asarray(c_1, jnp.float32)])
@@ -77,17 +80,17 @@ def cma_rank_mu_update(C: jnp.ndarray, Y: jnp.ndarray, w: jnp.ndarray,
         functools.partial(_kernel, n_k=n_k),
         grid=(n_i, n_j, n_k),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),                # coef (3,)
-            pl.BlockSpec((bk, bi), lambda i, j, k: (k, i)),       # Y (rows i)
-            pl.BlockSpec((bk, bj), lambda i, j, k: (k, j)),       # Y (rows j)
-            pl.BlockSpec((bk,), lambda i, j, k: (k,)),            # w
-            pl.BlockSpec((bi, bj), lambda i, j, k: (i, j)),       # C
-            pl.BlockSpec((bi,), lambda i, j, k: (i,)),            # p_c rows
-            pl.BlockSpec((bj,), lambda i, j, k: (j,)),            # p_c cols
+            _smem((3,)),                                          # coef (3,)
+            _block((bk, bi), lambda i, j, k: (k, i)),       # Y (rows i)
+            _block((bk, bj), lambda i, j, k: (k, j)),       # Y (rows j)
+            _block((bk, 1), lambda i, j, k: (k, 0)),        # w
+            _block((bi, bj), lambda i, j, k: (i, j)),       # C
+            _block((bi, 1), lambda i, j, k: (i, 0)),        # p_c rows
+            _block((1, bj), lambda i, j, k: (0, j)),        # p_c cols
         ],
-        out_specs=pl.BlockSpec((bi, bj), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((p_n, p_n), dt),
+        out_specs=_block((bi, bj), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((p_n, p_n), kdt),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
         interpret=interpret,
-    )(coef, Yp, Yp, wp, Cp, pcp, pcp)
-    return out[:n, :n]
+    )(coef, Yp, Yp, wp, Cp, pc_col, pc_row)
+    return out[:n, :n].astype(dt)

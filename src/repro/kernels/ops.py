@@ -23,9 +23,10 @@
                     counter-based, prefix-stable) stream from the default
                     row-keyed one — trajectories are not comparable across
                     tiers, which is why ``"auto"`` never selects it.  Off
-                    TPU (or if the Mosaic probe fails) the sample falls
-                    back to the XLA threefry ref — the bit-exact same
-                    stream, so the fallback never changes a trajectory.
+                    TPU the sample falls back to the XLA threefry ref — the
+                    bit-exact same stream, so the fallback never changes a
+                    trajectory; on TPU a kernel that fails to compile
+                    raises.
   * ``"auto"``    — "pallas" on TPU backends, "xla" otherwise.  Never
                     resolves to "pallas_rng": switching the RNG stream is
                     a trajectory-level decision the caller must make
@@ -99,22 +100,20 @@ def _kernel_tier(impl: str) -> bool:
 
 @functools.lru_cache(maxsize=1)
 def _rng_kernel_supported() -> bool:
-    """One-shot probe (satellite of the residency PR): can the in-kernel
-    RNG sample kernel actually compile and run on this backend?  Mosaic on
-    TPU is probed with a tiny real call; everywhere else the answer is a
-    static False — the XLA threefry ref IS the bit-exact same stream, so
-    the CPU fallback never changes a trajectory and interpret-mode kernels
-    stay a test-only surface (they are orders of magnitude too slow for
-    production CPU runs)."""
+    """One-shot probe: does the in-kernel RNG sample kernel compile and run
+    on this backend?  On TPU it makes a tiny real call and lets a Mosaic
+    error propagate — a kernel that does not compile is a fault to see,
+    not a reason to switch streams in silence.  Everywhere else the answer
+    is a static False: the XLA threefry ref IS the bit-exact same stream,
+    so the CPU fallback never changes a trajectory and interpret-mode
+    kernels stay a test-only surface (they are orders of magnitude too
+    slow for production CPU runs)."""
     if not _on_tpu():
         return False
-    try:
-        seeds = jnp.zeros((1, 2), jnp.uint32)
-        jax.block_until_ready(
-            cma_sample_z_rng(seeds, lam=8, n=128, dtype=jnp.float32))
-        return True
-    except Exception:                                   # pragma: no cover
-        return False
+    seeds = jnp.zeros((1, 2), jnp.uint32)
+    jax.block_until_ready(
+        cma_sample_z_rng(seeds, lam=8, n=128, dtype=jnp.float32))
+    return True
 
 
 def sample_transform(B, D, Z, impl: str = "auto"):
@@ -189,24 +188,6 @@ def _gen_impl(impl: str, n: int, dtype, fits=_megakernel_fits) -> str:
     return resolved
 
 
-def gen_sample(m, sigma, B, D, Z, impl: str = "auto"):
-    """Fused sampling: (Y, X) in one pass.
-
-    Slot-batched when ``Z`` carries a leading slot axis (ndim == 3) — the
-    stacked-slot ladder programs call this ONCE for all slots; per-slot
-    arrays are accepted too (a singleton slot axis is added for the kernel).
-    """
-    impl = _gen_impl(impl, Z.shape[-1], Z.dtype, fits=_sample_fits)
-    if not _kernel_tier(impl):
-        return ref.gen_sample(m, sigma, B, D, Z)
-    if Z.ndim == 3:
-        return cma_gen_sample(m, sigma, B, D, Z, interpret=not _on_tpu())
-    m1, B1, D1, Z1 = _stacked(m, B, D, Z)
-    Y, X = cma_gen_sample(m1, jnp.asarray(sigma)[None], B1, D1, Z1,
-                          interpret=not _on_tpu())
-    return Y[0], X[0]
-
-
 def _sep_slots(sep, S: int, n: int, dtype):
     """Broadcast a ``bbob.SepCoeffs`` (shared by all slots of a run, or
     already per-slot) to the kernel's per-slot layout."""
@@ -215,6 +196,57 @@ def _sep_slots(sep, S: int, n: int, dtype):
             jnp.broadcast_to(jnp.asarray(sep.f_opt, dtype), (S,)),
             jnp.broadcast_to(jnp.asarray(sep.mode, jnp.int32), (S,)),
             jnp.broadcast_to(jnp.asarray(sep.valid), (S,)))
+
+
+def _kernel_sample(m, sigma, B, D, zs, *, lam=None, sep=None):
+    """The sample kernels behind the four ``gen_sample*`` ops: ``zs`` is Z,
+    or the per-slot seeds when ``lam`` is given (in-kernel RNG).  Returns
+    (Y, X), or (Y, F) with ``sep``.  Per-slot arrays get a singleton slot
+    axis for the kernel.
+
+    Float64 state takes Y from the kernel and forms X = m + σ·Y (and the
+    separable fitness) in float64.  The kernels' own X is f32, a point of
+    the f32 lattice: its spacing near |x| ≈ 3 (2.4e-7) caps what a float64
+    campaign can reach (BBOB f2 at n = 40 stalled near 2e-8 on the chip).
+    The O(λn) axpy keeps the float64 mean's precision; the O(λn²) sampling
+    GEMM stays in the kernel.  The kernel's f32 X is still written and
+    dropped: a Y-only variant of the kernel (one output) inside the S1
+    shard_map segment loses its Mosaic config in the TPU compiler's x64
+    rewriter, and the v5e compile refuses it."""
+    rng = lam is not None
+    batched = B.ndim == 3
+    if not batched:
+        m, B, D, zs = _stacked(m, B, D, zs)
+        sigma = jnp.asarray(sigma)[None]
+    kw = dict(interpret=not _on_tpu(), **({"lam": lam} if rng else {}))
+    if jnp.dtype(m.dtype).itemsize > 4:
+        from repro.fitness import bbob
+        kernel = cma_gen_sample_rng if rng else cma_gen_sample
+        Y, _ = kernel(m, sigma, B, D, zs, **kw)
+        X = m[:, None, :] + jnp.asarray(sigma)[:, None, None] * Y
+        out = (Y, X if sep is None else bbob.separable_eval(X, sep))
+    elif sep is None:
+        kernel = cma_gen_sample_rng if rng else cma_gen_sample
+        out = kernel(m, sigma, B, D, zs, **kw)
+    else:
+        kernel = cma_gen_sample_rng_eval if rng else cma_gen_sample_eval
+        out = kernel(m, sigma, B, D, zs,
+                     *_sep_slots(sep, B.shape[0], B.shape[-1], m.dtype), **kw)
+    return tuple(out) if batched else tuple(o[0] for o in out)
+
+
+def gen_sample(m, sigma, B, D, Z, impl: str = "auto"):
+    """Fused sampling: (Y, X) in one pass.
+
+    Slot-batched when ``Z`` carries a leading slot axis (ndim == 3) — the
+    stacked-slot ladder programs call this ONCE for all slots; per-slot
+    arrays are accepted too (a singleton slot axis is added for the kernel).
+    For float64 state the kernel tier forms X in float64 (``_kernel_sample``).
+    """
+    impl = _gen_impl(impl, Z.shape[-1], Z.dtype, fits=_sample_fits)
+    if not _kernel_tier(impl):
+        return ref.gen_sample(m, sigma, B, D, Z)
+    return _kernel_sample(m, sigma, B, D, Z)
 
 
 def gen_sample_rng(m, sigma, B, D, seeds, lam: int, impl: str = "auto"):
@@ -229,33 +261,21 @@ def gen_sample_rng(m, sigma, B, D, seeds, lam: int, impl: str = "auto"):
     """
     impl = _gen_impl(impl, B.shape[-1], B.dtype, fits=_sample_fits)
     if impl == "pallas_rng" and _rng_kernel_supported():
-        if B.ndim == 3:
-            return cma_gen_sample_rng(m, sigma, B, D, seeds, lam=lam)
-        m1, B1, D1 = _stacked(m, B, D)
-        Y, X = cma_gen_sample_rng(m1, jnp.asarray(sigma)[None], B1, D1,
-                                  jnp.asarray(seeds)[None], lam=lam)
-        return Y[0], X[0]
+        return _kernel_sample(m, sigma, B, D, seeds, lam=lam)
     return ref.gen_sample_rng(m, sigma, B, D, seeds, lam)
 
 
 def gen_sample_eval(m, sigma, B, D, Z, sep, impl: str = "auto"):
     """Eval-fused sampling for separable fids: returns (Y, F) with the
-    fitness computed in the sample epilogue — X never materializes in HBM.
-    ``sep`` is a ``bbob.SepCoeffs``; on the XLA tiers the same algebra runs
-    as ``ref.gen_sample_eval`` (bit-identical to the dispatched
-    ``evaluate_dynamic`` on the same X)."""
+    fitness computed in the sample epilogue — X never materializes in HBM
+    (for f32 state; float64 state evaluates its float64 X,
+    ``_kernel_sample``).  ``sep`` is a ``bbob.SepCoeffs``; on the XLA tiers
+    the same algebra runs as ``ref.gen_sample_eval`` (bit-identical to the
+    dispatched ``evaluate_dynamic`` on the same X)."""
     impl = _gen_impl(impl, Z.shape[-1], Z.dtype, fits=_sample_fits)
     if not _kernel_tier(impl):
         return ref.gen_sample_eval(m, sigma, B, D, Z, sep)
-    batched = Z.ndim == 3
-    if not batched:
-        m, B, D, Z = _stacked(m, B, D, Z)
-        sigma = jnp.asarray(sigma)[None]
-    S, n = Z.shape[0], Z.shape[-1]
-    Y, F = cma_gen_sample_eval(m, sigma, B, D, Z,
-                               *_sep_slots(sep, S, n, Z.dtype),
-                               interpret=not _on_tpu())
-    return (Y, F) if batched else (Y[0], F[0])
+    return _kernel_sample(m, sigma, B, D, Z, sep=sep)
 
 
 def gen_sample_rng_eval(m, sigma, B, D, seeds, lam: int, sep,
@@ -266,16 +286,7 @@ def gen_sample_rng_eval(m, sigma, B, D, seeds, lam: int, sep,
     separable eval (same stream, same fitness algebra)."""
     impl = _gen_impl(impl, B.shape[-1], B.dtype, fits=_sample_fits)
     if impl == "pallas_rng" and _rng_kernel_supported():
-        batched = B.ndim == 3
-        if not batched:
-            m, B, D = _stacked(m, B, D)
-            sigma = jnp.asarray(sigma)[None]
-            seeds = jnp.asarray(seeds)[None]
-        S, n = B.shape[0], B.shape[-1]
-        Y, F = cma_gen_sample_rng_eval(m, sigma, B, D, seeds,
-                                       *_sep_slots(sep, S, n, B.dtype),
-                                       lam=lam)
-        return (Y, F) if batched else (Y[0], F[0])
+        return _kernel_sample(m, sigma, B, D, seeds, lam=lam, sep=sep)
     return ref.gen_sample_rng_eval(m, sigma, B, D, seeds, lam, sep)
 
 
@@ -288,10 +299,11 @@ def gen_update(C, B, D, p_sigma, p_c, Y, w, coef, impl: str = "auto"):
     ``(C_new, p_sigma_new, p_c_new, y_w)`` with matching batching.
 
     The megakernel computes in f32 regardless of the state dtype (the MXU
-    has no f64 path); f64 campaigns that need strict double-precision
-    trajectories should pin ``impl="xla"``.  Under ``impl="auto"``,
-    problems whose whole-matrix tiles exceed VMEM fall back to the fused
-    XLA ref (``_megakernel_fits``).
+    has no f64 path; compiled for TPU, f64 operands cross the kernel
+    boundary as f32 and the outputs are cast back); f64 campaigns that
+    need strict double-precision trajectories should pin ``impl="xla"``.
+    Under ``impl="auto"``, problems whose whole-matrix tiles exceed VMEM
+    fall back to the fused XLA ref (``_megakernel_fits``).
     """
     impl = _gen_impl(impl, C.shape[-1], C.dtype)
     if not _kernel_tier(impl):

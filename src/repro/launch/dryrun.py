@@ -245,7 +245,7 @@ def run_mesh_engine_dryrun(mesh, multi_pod: bool):
     widest rung bucket, one member per device) with the production mesh's
     devices re-viewed as a flat ("camp",) campaign axis — the paper's actual
     deployment (distributed/mesh_engine.py) as a first-class dry-run cell.
-    The psum/pmin carry reduction shows up in ``collective_bytes``."""
+    The psum/all_gather carry reduction shows up in ``collective_bytes``."""
     from repro.distributed import mesh_engine
     from repro.launch.mesh import make_campaign_mesh
 

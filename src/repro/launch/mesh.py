@@ -26,10 +26,7 @@ import numpy as np
 
 
 def _mk(shape, names, devices=None):
-    # jax.sharding.AxisType landed after 0.4.x; older jax only has untyped axes
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(names)
+    kw = {"axis_types": (jax.sharding.AxisType.Auto,) * len(names)}
     if devices is None:
         return jax.make_mesh(shape, names, **kw)
     devs = np.asarray(devices).reshape(shape)

@@ -35,10 +35,11 @@ carrying an ``arrival_s`` wall-clock offset; ``--synthetic N`` generates a
 mixed-dim BBOB trace instead.  Requests are fed to the server as their
 arrival time passes while the service loop runs — admission happens at the
 next segment boundary, exactly the streaming deployment the service exists
-for.  With ``--devices > 1`` the process re-execs itself under
-``--xla_force_host_platform_device_count`` (the bench_mesh pattern: the flag
-must precede jax's first import) and every lane runs one island per virtual
-device.  ``--resume`` restores the newest committed snapshot from
+for.  ``--devices N`` serves on the first N local devices (default: all of
+them), one island per device in every lane; the process never re-runs
+itself, so on a CPU the virtual devices come from the environment
+(``XLA_FLAGS=--xla_force_host_platform_device_count=N``) and on a TPU host
+they are the chips.  ``--resume`` restores the newest committed snapshot from
 ``--snapshot-dir`` instead of starting fresh (custom fitness callables
 cannot ride a snapshot — the CLI serves BBOB requests only).
 """
@@ -46,11 +47,7 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
 import sys
-
-_INNER_ENV = "_SERVE_CAMPAIGNS_INNER"
 
 
 def _parser():
@@ -60,7 +57,8 @@ def _parser():
     ap.add_argument("--synthetic", type=int, default=0,
                     help="generate N synthetic BBOB requests instead")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--devices", type=int, default=1)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="serve on the first N local devices (default: all)")
     ap.add_argument("--dims", default="4,8",
                     help="dim menu for --synthetic")
     ap.add_argument("--fids", default="1,8",
@@ -109,17 +107,7 @@ def _parser():
 
 
 def main(argv=None):
-    args = _parser().parse_args(argv)
-    if args.devices > 1 and os.environ.get(_INNER_ENV) != "1":
-        env = dict(os.environ)
-        env[_INNER_ENV] = "1"
-        env["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices} "
-            + env.get("XLA_FLAGS", ""))
-        cmd = [sys.executable, "-m", "repro.launch.serve_campaigns"]
-        cmd += list(argv) if argv is not None else sys.argv[1:]
-        return subprocess.run(cmd, check=True, env=env).returncode
-    return _serve(args)
+    return _serve(_parser().parse_args(argv))
 
 
 def _synthetic_requests(args):
@@ -157,7 +145,18 @@ def _serve(args):
 
     jax.config.update("jax_enable_x64", True)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.service import CampaignRequest, CampaignServer, QueueFull
+
+    enable_compile_cache()
+    devices = jax.devices()
+    if args.devices is not None:
+        if args.devices > len(devices):
+            raise SystemExit(
+                f"--devices {args.devices}: only {len(devices)} local "
+                f"devices (for a CPU rehearsal set XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={args.devices})")
+        devices = devices[:args.devices]
 
     if args.requests:
         with open(args.requests) as fh:
@@ -185,7 +184,7 @@ def _serve(args):
                              max_budget=max((r["budget"] for r in raw),
                                             default=args.budget),
                              rows_per_island=args.rows_per_island,
-                             devices=jax.devices(),
+                             devices=devices,
                              snapshot_dir=args.snapshot_dir,
                              snapshot_every=args.snapshot_every,
                              metrics_out=args.metrics_out)

@@ -86,6 +86,24 @@ def test_compile_count_le_number_of_buckets():
     assert res2.compiles <= n_buckets
 
 
+def test_campaign_rows_run_those_members_of_the_campaign():
+    """``rows`` runs a slice of the campaign's member layout (as one mesh
+    device holds it) on the same keys, instances and fid menu: each member
+    follows the whole campaign's trajectory."""
+    full = bucketed.run_campaign_bucketed(
+        bucketed.BucketedLadderEngine(**KW), fids=(1, 8), runs=2, seed=0)
+    rows = [1, 2]
+    part = bucketed.run_campaign_bucketed(
+        bucketed.BucketedLadderEngine(**KW), fids=(1, 8), runs=2, seed=0,
+        rows=rows)
+    assert part.members == [full.members[j] for j in rows]
+    np.testing.assert_array_equal(part.f_opt, full.f_opt[rows])
+    np.testing.assert_array_equal(part.total_fevals, full.total_fevals[rows])
+    np.testing.assert_allclose(part.best_f, full.best_f[rows],
+                               rtol=1e-5, atol=1e-7)
+    assert part.compiles <= KW["kmax_exp"] + 1
+
+
 def test_ecdf_equivalence_when_eigen_cadence_changes():
     """eigen_interval > 1: the nested scan's cadence is block-/segment-local
     rather than per-descent, so trajectories differ — but the engines must
@@ -205,7 +223,7 @@ def test_budget_counter_respects_x64_availability():
     carry = eng.init_carry(jax.random.PRNGKey(0))
     assert carry.total_fevals.dtype == jnp.int64       # x64 on (conftest)
 
-    with jax.experimental.disable_x64():
+    with jax.enable_x64(False):
         eng32 = ladder.LadderEngine(n=3, lam_start=4, kmax_exp=1,
                                     max_evals=2000, dtype="float32")
         carry32 = eng32.init_carry(jax.random.PRNGKey(0))
