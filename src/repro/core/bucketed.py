@@ -41,7 +41,6 @@ than campaign-global and the engines are ECDF-equivalent instead
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -52,6 +51,9 @@ from repro import obs
 from repro.core import ladder
 from repro.core.params import bucket_config, default_max_iter, ladder_params
 from repro.fitness import bbob
+
+# host phases (``obs.Tracer.phase``) also land on the JAX profiler's trace
+obs.set_annotator(jax.profiler.TraceAnnotation)
 
 
 @dataclasses.dataclass
@@ -289,9 +291,9 @@ def next_bucket(engine: BucketedLadderEngine, k_idx: np.ndarray,
 
 def drive_segments(engine: BucketedLadderEngine, carry: ladder.LadderCarry,
                    dispatch: Callable, max_segments: int = 10_000,
-                   time_axis: int = 1, pull: Optional[Callable] = None,
-                   budgets=None, overlap: Optional[bool] = None,
-                   supervisor=None):
+                   pull: Optional[Callable] = None, budgets=None,
+                   overlap: Optional[bool] = None, supervisor=None,
+                   parent: Optional[obs.Span] = None):
     """The host-side re-bucketing loop shared by campaign and single runs.
 
     ``dispatch(k, seg_gens, carry) -> (carry, trace)`` runs one jitted
@@ -299,10 +301,8 @@ def drive_segments(engine: BucketedLadderEngine, carry: ladder.LadderCarry,
     active flags, budget counters and member bests cross the device boundary
     — one batched ``pull`` (default ``pull_schedule``; the mesh engine passes
     a ``process_allgather`` variant); per-segment traces stay device-resident
-    until the driver finishes.  Returns ``(carry, trace, segments,
-    bucket_wall)``; segment traces are concatenated along ``time_axis`` (1
-    for vmapped campaigns whose leaves are (B, T, ...), 0 for a single run's
-    (T, ...)).
+    (``concat_traces`` gathers them).  Returns ``(carry, seg_traces,
+    segments, bucket_wall)``.
 
     ``overlap`` (default ``engine.overlap``) double-buffers the carries: the
     next segment is dispatched SPECULATIVELY with the previous bucket before
@@ -318,12 +318,16 @@ def drive_segments(engine: BucketedLadderEngine, carry: ladder.LadderCarry,
     overlap to help (the mesh S1 driver forces its psum scalars, so it pins
     ``overlap=False``).
 
-    Observability: the loop emits the ``bucketed_*`` series of
-    ``repro.obs.schema`` — segment wall, boundary sync, speculative-dispatch
-    hit/miss, useful vs padded evaluations and eigh-block counts — from
-    values that are ALREADY host-side here (the pull's np arrays and the
-    perf_counter deltas), so instrumentation adds no device syncs and no
-    recompiles (guarded in tests/test_obs.py).
+    Observability: each boundary is a chain of host phases
+    (``obs.Tracer.phase``, children of ``parent``): ``pull``, ``schedule``
+    (re-bucketing and bookkeeping), then a ``segment`` span over its
+    ``dispatch`` and (unoverlapped) ``wait`` phases.  The loop emits the
+    ``bucketed_*`` series of ``repro.obs.schema`` — segment wall, boundary
+    sync (each the span's own duration), speculative-dispatch hit/miss,
+    useful vs padded evaluations — from values that are ALREADY host-side
+    here (the pull's np arrays and the spans' clock readings), so
+    instrumentation adds no device syncs and no recompiles (guarded in
+    tests/test_obs.py).
 
     ``supervisor`` (a ``repro.fleet`` ``IslandSupervisor``) adds fleet
     supervision at three host-side points: a per-boundary snapshot/recovery
@@ -338,6 +342,7 @@ def drive_segments(engine: BucketedLadderEngine, carry: ladder.LadderCarry,
     pull = pull_schedule if pull is None else pull
     overlap = bool(engine.overlap) if overlap is None else bool(overlap)
     reg = obs.metrics()
+    tr = obs.tracer()
     seg_traces: List[ladder.LadderTrace] = []
     segments: List[dict] = []
     bucket_wall: Dict[int, float] = {}
@@ -360,66 +365,68 @@ def drive_segments(engine: BucketedLadderEngine, carry: ladder.LadderCarry,
         if overlap and k_prev is not None:
             # double-buffered carry: enqueue the likely next segment before
             # the host blocks on the schedule pull
+            with tr.phase("dispatch", parent, island="all", boundary=b,
+                          speculative=True):
+                if supervisor is not None:
+                    supervisor.before_dispatch(0, b)
+                spec = dispatch(k_prev, seg_len[k_prev], carry)
+        with tr.phase("pull", parent, island="all", boundary=b) as pull_span:
             if supervisor is not None:
-                supervisor.before_dispatch(0, b)
-            spec = dispatch(k_prev, seg_len[k_prev], carry)
-        pull_span = obs.tracer().start("pull", island="all", boundary=b)
-        t0 = time.perf_counter()
-        if supervisor is not None:
-            k_idx, active, fevals, best_f = supervisor.pull(
-                0, b, lambda: pull(carry))
-        else:
-            k_idx, active, fevals, best_f = pull(carry)
-        sync_s = time.perf_counter() - t0
-        obs.tracer().end(pull_span)
-        reg.histogram("bucketed_sync_s").observe(sync_s)
-        fev_sum = float(np.sum(fevals))
-        if fev_prev is not None:
-            reg.counter("bucketed_useful_evals_total").inc(
-                max(0.0, fev_sum - fev_prev))
-        fev_prev = fev_sum
-        if segments:
-            # the pull reflects the PREVIOUS segment's result — attach its
-            # post-segment best there (finite by then; None keeps the record
-            # strict-JSON-safe on the pathological all-inf fitness)
-            gb = float(best_f.min())
-            segments[-1]["global_best"] = gb if np.isfinite(gb) else None
-        _live, k = next_bucket(engine, k_idx, active, fevals, seg_len,
-                               budgets=budgets)
+                k_idx, active, fevals, best_f = supervisor.pull(
+                    0, b, lambda: pull(carry))
+            else:
+                k_idx, active, fevals, best_f = pull(carry)
+        sync_s = pull_span.dur
+        with tr.phase("schedule", parent, island="all", boundary=b):
+            reg.histogram("bucketed_sync_s").observe(sync_s)
+            fev_sum = float(np.sum(fevals))
+            if fev_prev is not None:
+                reg.counter("bucketed_useful_evals_total").inc(
+                    max(0.0, fev_sum - fev_prev))
+            fev_prev = fev_sum
+            if segments:
+                # the pull reflects the PREVIOUS segment's result — attach
+                # its post-segment best there (finite by then; None keeps
+                # the record strict-JSON-safe on the pathological all-inf
+                # fitness)
+                gb = float(best_f.min())
+                segments[-1]["global_best"] = gb if np.isfinite(gb) else None
+            _live, k = next_bucket(engine, k_idx, active, fevals, seg_len,
+                                   budgets=budgets)
         if k is None:
             break
-        seg_span = obs.tracer().start("segment", island="all",
-                                      bucket=int(k), boundary=b)
-        t0 = time.perf_counter()
         hit = spec is not None and k == k_prev
-        if hit:
-            carry, tr = spec
-        else:
-            if supervisor is not None:
-                supervisor.before_dispatch(0, b)
-            carry, tr = dispatch(k, seg_len[k], carry)
-        if not overlap:
-            jax.block_until_ready(carry.total_fevals)
-        wall = time.perf_counter() - t0
-        obs.tracer().end(
-            seg_span, spec=("hit" if hit
-                            else "miss" if spec is not None else "sync"))
-        seg_traces.append(tr)           # device-resident; transfer at the end
+        with tr.span("segment", parent, island="all", bucket=int(k),
+                     boundary=b) as seg_span:
+            with tr.phase("dispatch", seg_span, island="all", bucket=int(k),
+                          boundary=b):
+                if hit:
+                    carry, seg_tr = spec
+                else:
+                    if supervisor is not None:
+                        supervisor.before_dispatch(0, b)
+                    carry, seg_tr = dispatch(k, seg_len[k], carry)
+                seg_traces.append(seg_tr)   # device-resident until the end
+                if spec is not None:
+                    reg.counter("bucketed_spec_dispatch_total",
+                                outcome="hit" if hit else "miss").inc()
+                reg.counter("bucketed_segments_total", bucket=k).inc()
+                reg.counter("bucketed_padded_evals_total", bucket=k).inc(
+                    int(np.size(k_idx)) * seg_len[k] * (2 ** k)
+                    * engine.lam_start)
+            if not overlap:
+                with tr.phase("wait", seg_span, island="all", boundary=b):
+                    jax.block_until_ready(carry.total_fevals)
+            seg_span.attrs["spec"] = ("hit" if hit else
+                                      "miss" if spec is not None else "sync")
+        wall = seg_span.dur
+        reg.histogram("bucketed_segment_wall_s", bucket=k).observe(wall)
         seg = {"bucket": k, "gens": seg_len[k], "wall_s": round(wall, 5)}
         if overlap:
             # wall_s is dispatch-only here (no block); the host-blocked time
             # rides the pull instead
             seg["sync_s"] = round(sync_s, 5)
             seg["spec_hit"] = hit
-        if spec is not None:
-            reg.counter("bucketed_spec_dispatch_total",
-                        outcome="hit" if hit else "miss").inc()
-        reg.counter("bucketed_segments_total", bucket=k).inc()
-        reg.histogram("bucketed_segment_wall_s", bucket=k).observe(wall)
-        reg.counter("bucketed_padded_evals_total", bucket=k).inc(
-            int(np.size(k_idx)) * seg_len[k] * (2 ** k) * engine.lam_start)
-        reg.counter("bucketed_eigh_blocks_total", bucket=k).inc(
-            seg_len[k] // engine.interval)
         segments.append(seg)
         bucket_wall[k] = bucket_wall.get(k, 0.0) + wall + \
             (sync_s if overlap else 0.0)
@@ -427,17 +434,24 @@ def drive_segments(engine: BucketedLadderEngine, carry: ladder.LadderCarry,
     else:
         raise RuntimeError("segment driver did not converge "
                            f"within {max_segments} segments")
+    return carry, seg_traces, segments, bucket_wall
 
+
+def concat_traces(seg_traces: List[ladder.LadderTrace],
+                  carry: ladder.LadderCarry,
+                  time_axis: int = 1) -> ladder.LadderTrace:
+    """Gather ``drive_segments``' per-segment traces to the host, joined
+    along ``time_axis`` (1 for vmapped campaigns whose leaves are
+    (B, T, ...), 0 for a single run's (T, ...))."""
     if not seg_traces:
         # nothing could run (e.g. max_evals below one λ_start generation):
         # return a zero-length trace shaped like the padded engine's, so
         # every consumer sees the same empty-progress result
-        return carry, _empty_trace(carry, time_axis), segments, bucket_wall
-    trace = jax.tree_util.tree_map(
+        return _empty_trace(carry, time_axis)
+    return jax.tree_util.tree_map(
         lambda *xs: np.concatenate([np.asarray(x) for x in xs],
                                    axis=time_axis),
         *seg_traces)
-    return carry, trace, segments, bucket_wall
 
 
 def _empty_trace(carry: ladder.LadderCarry, time_axis: int) -> ladder.LadderTrace:
@@ -480,10 +494,9 @@ def run_bucketed_single(engine: BucketedLadderEngine, base_key: jax.Array,
             cache[ck] = jax.jit(run_seg)
         return cache[ck](base_key, c)
 
-    carry, trace, _segs, _walls = drive_segments(engine, carry, dispatch,
-                                                 max_segments, time_axis=0,
-                                                 supervisor=supervisor)
-    return carry, trace
+    carry, seg_traces, _segs, _walls = drive_segments(
+        engine, carry, dispatch, max_segments, supervisor=supervisor)
+    return carry, concat_traces(seg_traces, carry, time_axis=0)
 
 
 def run_campaign_bucketed(engine: BucketedLadderEngine, fids,
@@ -502,51 +515,68 @@ def run_campaign_bucketed(engine: BucketedLadderEngine, fids,
     ``rows`` runs only those members of the layout, with their own keys and
     instances and the whole campaign's fitness menu: e.g. the slice one
     mesh device holds, in a program of that slice's batch shape.
+
+    Host phases (``obs.Tracer.phase``) under one ``campaign`` root span:
+    ``keys``, ``instances``, ``init``, then ``drive_segments``' per
+    boundary, then ``finish``.  An exception (a caller's stop raised in a
+    dispatch) ends every open span, the root with ``status="stopped"``.
     """
     fids = tuple(fids)
-    members = [(f, i, r) for f in fids for i in instances for r in range(runs)]
-    base = jax.random.PRNGKey(seed)
-    keys = jnp.stack([jax.random.fold_in(base, j) for j in range(len(members))])
+    layout = [(f, i, r) for f in fids for i in instances for r in range(runs)]
     if rows is not None:
         rows = [int(j) for j in rows]
-        members = [members[j] for j in rows]
-        keys = keys[np.asarray(rows)]
-    insts = [bbob.make_instance(f, engine.n, i, engine.full.cfg.jdtype)
-             for (f, i, _r) in members]
-    stacked = bbob.stack_instances(insts)
-    branch_fids = tuple(sorted(set(fids)))
-    carry = engine._init_runner(keys)
-
-    fused_menu = (bbob.eval_fusion_enabled()
-                  and all(f in bbob.FUSABLE_FIDS for f in branch_fids))
+    members = layout if rows is None else [layout[j] for j in rows]
     reg = obs.metrics()
+    tr = obs.tracer()
+    with tr.span("campaign", members=len(members), fids=list(fids)) as root:
+        with tr.phase("keys", root, island="all") as keys_span:
+            base = jax.random.PRNGKey(seed)
+            keys = jnp.stack([jax.random.fold_in(base, j)
+                              for j in range(len(layout))])
+            if rows is not None:
+                keys = keys[np.asarray(rows)]
+        with tr.phase("instances", root, island="all"):
+            insts = [bbob.make_instance(f, engine.n, i, engine.full.cfg.jdtype)
+                     for (f, i, _r) in members]
+            stacked = bbob.stack_instances(insts)
+        with tr.phase("init", root, island="all") as init_span:
+            carry = engine._init_runner(keys)
+        reg.histogram("bucketed_campaign_setup_s").observe(
+            init_span.t1 - keys_span.t0)
+        branch_fids = tuple(sorted(set(fids)))
+        fused_menu = (bbob.eval_fusion_enabled()
+                      and all(f in bbob.FUSABLE_FIDS for f in branch_fids))
 
-    def dispatch(k, seg_gens, c):
-        runner = engine.segment_runner(k, branch_fids, seg_gens)
-        if fused_menu:
-            # whole-menu-separable segments run the eval-fused sample
-            # epilogue — count their generations (host-known statics only:
-            # no device sync, no recompile)
-            reg.counter("bucketed_eval_fused_generations_total").inc(
-                int(seg_gens))
-        return runner(keys, stacked, c)
+        def dispatch(k, seg_gens, c):
+            runner = engine.segment_runner(k, branch_fids, seg_gens)
+            if fused_menu:
+                # whole-menu-separable segments run the eval-fused sample
+                # epilogue — count their generations (host-known statics
+                # only: no device sync, no recompile)
+                reg.counter("bucketed_eval_fused_generations_total").inc(
+                    int(seg_gens))
+            return runner(keys, stacked, c)
 
-    carry, trace, segments, bucket_wall = drive_segments(
-        engine, carry, dispatch, max_segments)
-    lam_start, kmax = engine.lam_start, engine.kmax_exp
-    useful = _useful_evals_per_rung(trace, lam_start, kmax)
-    B = len(members)
-    padded = sum(B * s["gens"] * (2 ** s["bucket"]) * lam_start
-                 for s in segments)
-    return BucketedCampaignResult(
-        members=members,
-        f_opt=np.asarray([i.f_opt for i in insts], np.float64),
-        best_f=np.asarray(carry.best_f),
-        best_x=np.asarray(carry.best_x),
-        total_fevals=np.asarray(carry.total_fevals),
-        trace=trace,
-        compiles=engine.compiles(),
-        segments=segments,
-        bucket_wall_s={k: round(v, 5) for k, v in bucket_wall.items()},
-        useful_evals=int(sum(useful.values())),
-        padded_evals=int(padded))
+        carry, seg_traces, segments, bucket_wall = drive_segments(
+            engine, carry, dispatch, max_segments, parent=root)
+        with tr.phase("finish", root, island="all") as finish_span:
+            trace = concat_traces(seg_traces, carry)
+            lam_start, kmax = engine.lam_start, engine.kmax_exp
+            useful = _useful_evals_per_rung(trace, lam_start, kmax)
+            B = len(members)
+            padded = sum(B * s["gens"] * (2 ** s["bucket"]) * lam_start
+                         for s in segments)
+            result = BucketedCampaignResult(
+                members=members,
+                f_opt=np.asarray([i.f_opt for i in insts], np.float64),
+                best_f=np.asarray(carry.best_f),
+                best_x=np.asarray(carry.best_x),
+                total_fevals=np.asarray(carry.total_fevals),
+                trace=trace,
+                compiles=engine.compiles(),
+                segments=segments,
+                bucket_wall_s={k: round(v, 5) for k, v in bucket_wall.items()},
+                useful_evals=int(sum(useful.values())),
+                padded_evals=int(padded))
+        reg.histogram("bucketed_campaign_finish_s").observe(finish_span.dur)
+    return result

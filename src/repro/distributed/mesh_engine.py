@@ -385,7 +385,7 @@ class MeshCampaignEngine:
                                          fitness_fn, cache=local_cache)
             args = (keys, c) if insts is None else (keys, insts, c)
             # no island attr on purpose: drive_segments already covers this
-            # wall with its island="all" segment span — a second island-
+            # wall with its island="all" dispatch phase — a second island-
             # attributed span would double-count busy time in the digest
             sp = obs.tracer().start("dispatch", strategy="ordered",
                                     bucket=int(k))
@@ -419,10 +419,10 @@ class MeshCampaignEngine:
         # it just accepted before deciding whether another bucket exists
         # (a supervisor sees S1 as ONE island — its failure domain is the
         # whole mesh program, so recovery restarts the whole-batch carry)
-        carry, trace, segments, bucket_wall = bucketed.drive_segments(
-            self.bucketed, carry, dispatch, max_segments,
-            time_axis=1, pull=pull, overlap=self.overlap,
-            supervisor=supervisor)
+        carry, seg_traces, segments, bucket_wall = bucketed.drive_segments(
+            self.bucketed, carry, dispatch, max_segments, pull=pull,
+            overlap=self.overlap, supervisor=supervisor)
+        trace = bucketed.concat_traces(seg_traces, carry)
         return carry, trace, segments, bucket_wall, exchange, None
 
     def _drive_concurrent(self, keys, insts, carry, branch_fids, fitness_fn,

@@ -21,7 +21,7 @@ from repro.obs.registry import (Counter, Gauge, Histogram,     # noqa: F401
 from repro.obs.schema import (SCHEMA, SPECS, MetricSpec,       # noqa: F401
                               log_buckets, render_markdown)
 from repro.obs.trace import (Span, Tracer, reset_tracer,       # noqa: F401
-                             set_tracer, to_chrome, tracer,
-                             validate_chrome)
+                             set_annotator, set_tracer, to_chrome,
+                             tracer, validate_chrome)
 from repro.obs.recorder import (FlightRecorder, recorder,      # noqa: F401
                                 reset_recorder, set_recorder)

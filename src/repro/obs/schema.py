@@ -91,7 +91,8 @@ SCHEMA: Tuple[MetricSpec, ...] = (
     MetricSpec("bucketed_sync_s", HISTOGRAM, "s", (),
                "core/bucketed.py:drive_segments",
                "Boundary host sync: the ONE batched schedule pull "
-               "(pull_schedule / pull_schedule_allgather) per segment."),
+               "(pull_schedule / pull_schedule_allgather) per segment; "
+               "the `pull` phase's duration."),
     MetricSpec("bucketed_spec_dispatch_total", COUNTER, "segments",
                ("outcome",),
                "core/bucketed.py:drive_segments",
@@ -107,10 +108,15 @@ SCHEMA: Tuple[MetricSpec, ...] = (
                "Device evaluation rows paid per dispatched segment "
                "(rows x gens x lambda_bucket); padding waste = "
                "padded/useful."),
-    MetricSpec("bucketed_eigh_blocks_total", COUNTER, "blocks", ("bucket",),
-               "core/bucketed.py:drive_segments",
-               "Batched eigendecomposition blocks executed "
-               "(seg_gens/eigen_interval per dispatched segment)."),
+    MetricSpec("bucketed_campaign_setup_s", HISTOGRAM, "s", (),
+               "core/bucketed.py:run_campaign_bucketed",
+               "A campaign's entry before its first boundary pull: the "
+               "`keys`, `instances` and `init` phases, from the start of "
+               "`keys` to the end of `init`."),
+    MetricSpec("bucketed_campaign_finish_s", HISTOGRAM, "s", (),
+               "core/bucketed.py:run_campaign_bucketed",
+               "A finished campaign's `finish` phase: the segment traces "
+               "gathered to the host and the result built."),
     MetricSpec("bucketed_eval_fused_generations_total", COUNTER,
                "generations", (),
                "core/bucketed.py:run_campaign_bucketed",
@@ -243,15 +249,6 @@ SCHEMA: Tuple[MetricSpec, ...] = (
                "trigger=skew (occupancy imbalance) | rejoin (island "
                "re-admitted)."),
     # -- causal tracing + flight recorder (obs/trace.py, obs/recorder.py) ---
-    MetricSpec("service_trace_spans_total", COUNTER, "spans", ("span",),
-               "obs/trace.py:Tracer.end",
-               "Finished spans appended to the process-wide tracer ring, "
-               "by span name (job|queued|running|recover|segment|pull|"
-               "dispatch|block|compile|...)."),
-    MetricSpec("service_trace_active", GAUGE, "spans", (),
-               "obs/trace.py:Tracer.start/end",
-               "Currently open (started, not yet ended) spans — exposed "
-               "on /statusz as the live-trace count."),
     MetricSpec("service_trace_dropped_total", COUNTER, "spans", (),
                "obs/trace.py:Tracer.end",
                "Finished spans evicted from the bounded tracer ring "

@@ -25,6 +25,12 @@ one trace.  Island-side spans ("segment", "pull", "dispatch", "block",
 "compile") carry ``island``/``lane`` attrs and render as per-island lane
 tracks.
 
+Leaf host phases (``Tracer.phase``) are also written into the JAX
+profiler's trace, as annotations of their bare name on the emitting
+thread, so a device trace's idle gaps can be named by the phase the host
+was in.  The annotation factory is installed from the JAX side of the
+program (``set_annotator``): this module never imports jax.
+
 Read surfaces
 -------------
 
@@ -52,14 +58,30 @@ import os
 import sys
 import threading
 import time
-from typing import Dict, List, Optional, Tuple, Union
+from typing import (Callable, ContextManager, Dict, List, Optional, Tuple,
+                    Union)
 
 from repro.obs import registry as _registry
 
 #: span names whose wall counts as "busy" vs "blocked" in the offline
 #: per-island digest (everything else on an island track is neutral).
-BUSY_NAMES = ("segment", "dispatch", "compile")
-BLOCKED_NAMES = ("pull", "block", "sync", "exchange")
+BUSY_NAMES = ("segment", "dispatch", "compile", "keys", "instances", "init",
+              "schedule", "finish")
+BLOCKED_NAMES = ("pull", "block", "sync", "exchange", "wait")
+
+#: the profiler annotation ``Tracer.phase`` enters: ``factory(name)`` gives
+#: a context manager (``jax.profiler.TraceAnnotation``, installed by
+#: ``repro.core.bucketed``), or None where nothing is installed.
+_ANNOTATOR: Optional[Callable[[str], ContextManager]] = None
+
+
+def set_annotator(factory: Optional[Callable[[str], ContextManager]]
+                  ) -> Optional[Callable[[str], ContextManager]]:
+    """Install the profiler-annotation factory of ``Tracer.phase`` (None
+    removes it); returns the previous one."""
+    global _ANNOTATOR
+    prev, _ANNOTATOR = _ANNOTATOR, factory
+    return prev
 
 
 @dataclasses.dataclass
@@ -126,8 +148,6 @@ class Tracer:
             s = Span(trace_id=trace_id, span_id=sid, parent_id=parent_id,
                      name=name, t0=t0, attrs=dict(attrs))
             self._open[sid] = s
-        reg = _registry.metrics()
-        reg.gauge("service_trace_active").set(len(self._open))
         return s
 
     def end(self, span: Span, **attrs) -> Span:
@@ -144,20 +164,37 @@ class Tracer:
                 _registry.metrics().counter(
                     "service_trace_dropped_total").inc()
             self._ring.append(span)
-        reg = _registry.metrics()
-        reg.counter("service_trace_spans_total", span=span.name).inc()
-        reg.gauge("service_trace_active").set(len(self._open))
         return span
 
     @contextlib.contextmanager
     def span(self, name: str, parent: Union[Span, int, None] = None,
              trace_id: Optional[int] = None, **attrs):
+        """A span around the block; one that an exception leaves ends with
+        ``status="stopped"``."""
         s = self.start(name, parent=parent, trace_id=trace_id, **attrs)
         try:
             yield s
+        except BaseException:
+            if s.t1 is None:
+                self.end(s, status="stopped")
+            raise
         finally:
             if s.t1 is None:
                 self.end(s)
+
+    @contextlib.contextmanager
+    def phase(self, name: str, parent: Union[Span, int, None] = None,
+              **attrs):
+        """A leaf host phase: a span as ``span()`` records it, and, for
+        its duration and on this thread, a profiler annotation of the bare
+        ``name`` (where ``set_annotator`` installed one).  The attrs stay
+        on the span only.  Enclosing spans use ``span()``: on the
+        profiler's host line an annotation sorts before the ones inside
+        it, so an enclosing one would name every gap its children cover."""
+        annotation = (contextlib.nullcontext() if _ANNOTATOR is None
+                      else _ANNOTATOR(name))
+        with self.span(name, parent=parent, **attrs) as s, annotation:
+            yield s
 
     def event(self, name: str, parent: Union[Span, int, None] = None,
               trace_id: Optional[int] = None, **attrs) -> Span:
@@ -350,7 +387,8 @@ def load_jsonl(path: str) -> List[dict]:
 def summarize(spans: List[dict]) -> dict:
     """Offline trace digest: per-job critical path (the sequential chain
     of lifecycle children under each "job" root) and per-island
-    busy/blocked/idle fractions over the island's observed window."""
+    busy/blocked/idle fractions over the island's observed window
+    (``BUSY_NAMES`` busy, ``BLOCKED_NAMES`` blocked, the rest idle)."""
     by_id = {s["span_id"]: s for s in spans}
     children: Dict[int, List[dict]] = {}
     for s in spans:
@@ -375,6 +413,12 @@ def summarize(spans: List[dict]) -> dict:
                          sum(c["dur_s"] for c in kids), 9),
                      "phases": phases})
 
+    # each instant counts once: a span with counted children (a "segment"
+    # over its "dispatch" and "wait") leaves its time to them
+    counted = BUSY_NAMES + BLOCKED_NAMES
+    enclosing = {s.get("parent_id") for s in spans
+                 if s["name"] in counted
+                 and s["attrs"].get("island") is not None}
     islands: Dict[str, dict] = {}
     for s in spans:
         isl = s["attrs"].get("island")
@@ -387,6 +431,8 @@ def summarize(spans: List[dict]) -> dict:
         rec["spans"] += 1
         rec["t_lo"] = min(rec["t_lo"], s["t0"])
         rec["t_hi"] = max(rec["t_hi"], s["t1"])
+        if s["span_id"] in enclosing:
+            continue
         if s["name"] in BUSY_NAMES:
             rec["busy_s"] += s["dur_s"]
         elif s["name"] in BLOCKED_NAMES:

@@ -221,7 +221,7 @@ def test_bucketed_run_emits_series_without_new_syncs(fresh_metrics,
     assert set(walls) == set(segs)
     assert sum(h.count for h in walls.values()) == syncs.count - 1
     assert all(s.value > 0 for s in
-               series(reg, "bucketed_eigh_blocks_total").values())
+               series(reg, "bucketed_padded_evals_total").values())
 
 
 def test_service_two_job_run_emits_documented_series(fresh_metrics,

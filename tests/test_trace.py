@@ -20,6 +20,8 @@ Four things are pinned here:
   PR-6 zero-new-device-syncs pin holds WITH tracing enabled
   (``jax.device_get`` count == boundary-pull observations).
 """
+import contextlib
+import glob
 import json
 import subprocess
 import sys
@@ -30,6 +32,7 @@ import jax.numpy as jnp
 import pytest
 
 from conftest import hermetic_subproc_env
+from repro.core import bucketed
 from repro.core.ipop import run_ipop
 from repro.obs import registry as reg_mod
 from repro.obs import trace as trace_mod
@@ -84,18 +87,16 @@ def test_span_chain_and_series(fresh_metrics, fresh_tracer):
     child = tr.start("queued", parent=root, job=7)
     assert child.trace_id == root.trace_id          # chained trace
     assert child.parent_id == root.span_id
-    assert fresh_metrics.gauge("service_trace_active").value == 2
+    assert tr.active_count() == 2
     tr.end(child)
     tr.end(root, status="done", reason="")
     assert root.attrs["status"] == "done"           # end-attrs merge
     assert root.t1 >= root.t0 and root.dur >= 0
     assert [s.name for s in tr.finished()] == ["queued", "job"]
     assert tr.active_count() == 0
-    assert fresh_metrics.gauge("service_trace_active").value == 0
-    got = {dict(lkey)["span"]: s.value
-           for lkey, s in series(fresh_metrics,
-                                 "service_trace_spans_total").items()}
-    assert got == {"queued": 1, "job": 1}
+    # the tracer keeps no self-metrics of its own in the registry
+    assert not any(n.startswith("service_trace_")
+                   for n, _l in fresh_metrics._series)
     # wall anchor maps perf time to unix time
     assert abs(tr.unix(root.t0) - tr.epoch_unix
                - (root.t0 - tr.epoch_perf)) < 1e-6
@@ -123,6 +124,40 @@ def test_span_context_manager_and_event(fresh_metrics, fresh_tracer):
     assert s.t1 is not None and s.attrs["hit"] is True
     ev = tr.event("health", island=0, state="dead")
     assert ev.t1 == ev.t0 or ev.t1 > ev.t0          # instantaneous marker
+    assert tr.active_count() == 0
+
+
+def test_phase_is_a_span_inside_an_annotation_of_its_bare_name(
+        fresh_tracer):
+    tr = fresh_tracer
+    entered = []
+
+    @contextlib.contextmanager
+    def annotation(name, **kw):
+        entered.append((name, kw, tr.active_count()))
+        try:
+            yield
+        finally:
+            entered.append(("exit", name, tr.active_count()))
+
+    prev = trace_mod.set_annotator(annotation)
+    try:
+        with tr.span("campaign") as root:
+            with tr.phase("keys", root, island="all") as s:
+                pass
+            with pytest.raises(KeyError):
+                with tr.phase("dispatch", root, bucket=0):
+                    raise KeyError("stop")
+    finally:
+        trace_mod.set_annotator(prev)
+    # only leaf phases are annotated, by bare name, inside their spans
+    assert entered == [("keys", {}, 2), ("exit", "keys", 2),
+                       ("dispatch", {}, 2), ("exit", "dispatch", 2)]
+    assert s.parent_id == root.span_id and s.trace_id == root.trace_id
+    assert s.attrs == {"island": "all"} and s.t1 >= s.t0
+    stopped = {x.name: x.attrs.get("status") for x in tr.finished()}
+    assert stopped == {"keys": None, "dispatch": "stopped",
+                       "campaign": None}
     assert tr.active_count() == 0
 
 
@@ -238,6 +273,24 @@ def test_summarize_digest_is_exact():
     assert isl["idle_frac"] == pytest.approx(0.6)
 
 
+def test_summarize_counts_each_instant_once():
+    spans = [
+        # a segment span over its dispatch and wait phases counts once
+        _sp(1, "segment", 0.0, 3.0, island="all", bucket=0),
+        _sp(2, "dispatch", 0.0, 0.5, parent=1, trace=1, island="all"),
+        _sp(3, "wait", 0.5, 3.0, parent=1, trace=1, island="all"),
+        # a leaf segment (no counted children) is busy as before
+        _sp(4, "segment", 3.0, 4.0, island="all", bucket=1),
+        _sp(5, "pull", 4.0, 4.5, island="all", boundary=1),
+        _sp(6, "schedule", 4.5, 4.6, island="all", boundary=1),
+        _sp(7, "finish", 4.6, 5.0, island="all"),
+    ]
+    isl = summarize(spans)["islands"]["all"]
+    assert isl["busy_s"] == pytest.approx(0.5 + 1.0 + 0.1 + 0.4)
+    assert isl["blocked_s"] == pytest.approx(2.5 + 0.5)
+    assert isl["idle_frac"] == pytest.approx(0.0, abs=1e-6)
+
+
 def test_trace_cli_summarize_and_validate(fresh_metrics, fresh_tracer,
                                           tmp_path):
     tr = fresh_tracer
@@ -276,14 +329,14 @@ def test_schema_check_exits_nonzero_with_unified_diff(tmp_path):
         [sys.executable, "-m", "repro.obs.schema", "--check", str(doc)],
         check=True, cwd=ROOT, env=env)
     doc.write_text(doc.read_text().replace(
-        "service_trace_spans_total", "service_trace_spams_total"))
+        "service_trace_dropped_total", "service_trace_dropt_total"))
     r = subprocess.run(
         [sys.executable, "-m", "repro.obs.schema", "--check", str(doc)],
         cwd=ROOT, env=env, capture_output=True, text=True)
     assert r.returncode != 0
     assert "---" in r.stderr and "+++" in r.stderr and "@@" in r.stderr
-    assert "-| `service_trace_spams_total`" in r.stderr
-    assert "+| `service_trace_spans_total`" in r.stderr
+    assert "-| `service_trace_dropt_total`" in r.stderr
+    assert "+| `service_trace_dropped_total`" in r.stderr
 
 
 # ---------------------------------------------------------------------------
@@ -385,10 +438,15 @@ def test_bucketed_busy_fraction_reconciles_with_segment_wall(fresh_metrics,
     seg_wall = sum(h.sum for h in
                    series(fresh_metrics, "bucketed_segment_wall_s").values())
     sync_wall = fresh_metrics.histogram("bucketed_sync_s").sum
+    wait_wall = sum(s.dur for s in fresh_tracer.finished()
+                    if s.name == "wait")
     # span-derived busy/blocked seconds bracket the histogram walls: the
-    # spans cover the same host regions plus O(us) bookkeeping
-    assert isl["busy_s"] == pytest.approx(seg_wall, rel=0.2, abs=0.05)
-    assert isl["blocked_s"] == pytest.approx(sync_wall, rel=0.2, abs=0.05)
+    # segment span counts once, through its busy dispatch and blocked wait
+    # phases; the pulls are blocked; the rest is O(us) bookkeeping
+    assert isl["busy_s"] == pytest.approx(seg_wall - wait_wall,
+                                          rel=0.2, abs=0.05)
+    assert isl["blocked_s"] == pytest.approx(sync_wall + wait_wall,
+                                             rel=0.2, abs=0.05)
     assert isl["busy_frac"] + isl["blocked_frac"] + isl["idle_frac"] \
         == pytest.approx(1.0, abs=1e-3)
     n_segs = sum(s.value for s in
@@ -436,7 +494,120 @@ def test_service_trace_reconciles_with_lifecycle_and_sync_pin(
     assert compiles
     assert sum(1 for s in compiles if not s.attrs["hit"]) \
         <= (KW["kmax_exp"] + 1) * len(srv.lanes)
-    # spans counter total == ring content (nothing dropped on this run)
-    emitted = sum(s.value for s in
-                  series(fresh_metrics, "service_trace_spans_total").values())
-    assert emitted == len(spans) and fresh_tracer.dropped == 0
+    # the ring holds every span of the run (nothing dropped)
+    assert fresh_tracer.dropped == 0
+    assert fresh_metrics.counter("service_trace_dropped_total").value == 0
+
+
+# ---------------------------------------------------------------------------
+# the campaign path's host phases, on the tracer and the profiler's clock
+# ---------------------------------------------------------------------------
+
+#: the leaf phases of ``run_campaign_bucketed``, each annotated
+PHASES = ("keys", "instances", "init", "pull", "schedule", "dispatch",
+          "wait", "finish")
+
+
+@pytest.fixture(scope="module")
+def small_engine():
+    """n = 4, two fids, one instance; compiled once for the module."""
+    eng = bucketed.BucketedLadderEngine(n=4, max_evals=3000, **KW)
+    bucketed.run_campaign_bucketed(eng, fids=(1, 8), instances=(1,))
+    return eng
+
+
+def _campaign(eng):
+    return bucketed.run_campaign_bucketed(eng, fids=(1, 8), instances=(1,),
+                                          seed=3)
+
+
+def test_campaign_phases_lie_on_the_profiler_host_line(
+        small_engine, fresh_tracer, tmp_path):
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        _campaign(small_engine)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                       for e in line.events
+                       if e.name in PHASES + ("campaign", "segment")]
+                if evs:
+                    lines[line.name] = evs
+    (name, evs), = lines.items()                    # one host line...
+    assert name.startswith(("python", "main"))
+    # ...holding only the leaf phases, by bare name, in the tracer's order
+    leaves = sorted((s for s in fresh_tracer.finished() if s.name in PHASES),
+                    key=lambda s: s.t0)
+    assert [e[0] for e in evs] == [s.name for s in leaves]
+    assert leaves[:3] == [s for s in leaves if s.name in
+                          ("keys", "instances", "init")]
+    assert leaves[-1].name == "finish"
+    assert all(a[2] <= b[1] for a, b in zip(evs, evs[1:]))  # no overlap
+
+
+def test_campaign_spans_share_one_trace_and_match_histograms(
+        small_engine, fresh_metrics, fresh_tracer, count_device_get):
+    _campaign(small_engine)
+    spans = fresh_tracer.finished()
+    (root,) = [s for s in spans if s.name == "campaign"]
+    assert {s.trace_id for s in spans} == {root.trace_id}
+    assert root.attrs == {"members": 2, "fids": [1, 8]}
+    count = {n: sum(1 for s in spans if s.name == n)
+             for n in PHASES + ("segment",)}
+    sync = fresh_metrics.histogram("bucketed_sync_s")
+    setup = fresh_metrics.histogram("bucketed_campaign_setup_s")
+    finish = fresh_metrics.histogram("bucketed_campaign_finish_s")
+    walls = series(fresh_metrics, "bucketed_segment_wall_s").values()
+    # one pull per device sync (no new sync) and per histogram observation
+    assert count["pull"] == sync.count == count_device_get["n"]
+    assert count["schedule"] == count["pull"]
+    assert count["segment"] == count["dispatch"] == count["wait"] \
+        == sum(h.count for h in walls) == sync.count - 1
+    assert count["keys"] == count["instances"] == count["init"] \
+        == setup.count == 1
+    assert count["finish"] == finish.count == 1
+    # the histograms read the spans' own clock readings
+    by = {n: [s for s in spans if s.name == n] for n in PHASES}
+    assert sync.sum == pytest.approx(sum(s.dur for s in by["pull"]))
+    assert setup.sum == pytest.approx(by["init"][0].t1 - by["keys"][0].t0)
+    assert finish.sum == pytest.approx(by["finish"][0].dur)
+    for s in spans:                                  # parents as documented
+        want = ("segment" if s.name in ("dispatch", "wait") else
+                None if s.name == "campaign" else "campaign")
+        assert (s.parent_id and next(p.name for p in spans
+                                     if p.span_id == s.parent_id)) == want
+
+
+class _Stop(Exception):
+    pass
+
+
+def test_campaign_stopped_in_dispatch_closes_every_span(
+        small_engine, fresh_metrics, fresh_tracer, monkeypatch):
+    calls = {"n": 0}
+    real = small_engine.segment_runner
+
+    def stopping(k, fids, gens):
+        fn = real(k, fids, gens)
+
+        def run(*args):
+            calls["n"] += 1
+            if calls["n"] == 2:
+                raise _Stop
+            return fn(*args)
+        return run
+
+    monkeypatch.setattr(small_engine, "segment_runner", stopping)
+    with pytest.raises(_Stop):
+        _campaign(small_engine)
+    assert fresh_tracer.active_count() == 0
+    status = {s.name: s.attrs.get("status")
+              for s in fresh_tracer.finished()}
+    assert status["campaign"] == status["segment"] == status["dispatch"] \
+        == "stopped"
+    assert "finish" not in status
+    assert fresh_metrics.histogram("bucketed_campaign_finish_s").count == 0
+    assert fresh_metrics.histogram("bucketed_campaign_setup_s").count == 1
